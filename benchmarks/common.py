@@ -30,7 +30,12 @@ def time_call(fn, *args, iters: int = 5, warmup: int = 2):
 
 
 def run_with_devices(snippet: str, n_devices: int, timeout: int = 900) -> str:
+    """Run ``snippet`` in a child interpreter that emulates ``n_devices``
+    ranks on host CPU devices.  The child is pinned to the CPU backend so
+    that, on an accelerator host, it never competes for the chip with a
+    parent that already holds it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", snippet], env=env,

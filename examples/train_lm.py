@@ -93,4 +93,6 @@ def next_pow2(n: int) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

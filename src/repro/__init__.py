@@ -1,48 +1,3 @@
 """JAX reproduction of 'Design and Implementation of an Analysis Pipeline
 for Heterogeneous Data': heterogeneous pilot runtime, distributed dataframe
 operators, and the model/training substrate."""
-import jax
-
-if not hasattr(jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental only and spells the
-    # varying-manual-axes check `check_rep`; the codebase uses the stable
-    # jax.shard_map spelling with `check_vma`.
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @functools.wraps(_shard_map)
-    def _compat_shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    jax.shard_map = _compat_shard_map
-
-if not hasattr(jax.sharding, "AxisType"):
-    # jax < 0.6 has no sharding-in-types axis kinds; everything behaves as
-    # Auto, so accept and drop the annotations.
-    import enum
-    import functools as _ft
-
-    class _AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    jax.sharding.AxisType = _AxisType
-
-    if hasattr(jax, "make_mesh"):   # absent before jax 0.4.35
-        _make_mesh = jax.make_mesh
-
-        @_ft.wraps(_make_mesh)
-        def _compat_make_mesh(*args, **kwargs):
-            kwargs.pop("axis_types", None)
-            return _make_mesh(*args, **kwargs)
-
-        jax.make_mesh = _compat_make_mesh
-
-if not hasattr(jax.lax, "axis_size"):
-    # jax < 0.5: psum of a literal 1 over a named axis is statically folded
-    # to the axis size — the classic spelling of axis_size.
-    jax.lax.axis_size = lambda name: jax.lax.psum(1, name)

@@ -264,8 +264,9 @@ class ProcessExecutor(QueueEventExecutor):
                  if not f.startswith("--xla_force_host_platform_device_count")]
         flags.append(f"--xla_force_host_platform_device_count={k}")
         env["XLA_FLAGS"] = " ".join(flags)
-        # host devices only exist on the CPU platform; never let a worker
-        # grab the parent's accelerator unless explicitly overridden
+        # workers are host-only: host devices exist only on the CPU
+        # platform, and a chip belongs to the one process holding it (run
+        # chip tasks under ThreadExecutor in that process)
         env["JAX_PLATFORMS"] = "cpu"
         import repro
         src = str(Path(repro.__file__).resolve().parents[1])

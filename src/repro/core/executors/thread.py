@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import traceback
 from typing import Any, Optional
 
 from repro.core.executors.base import ExecEvent, QueueEventExecutor
@@ -70,8 +71,11 @@ class ThreadExecutor(QueueEventExecutor):
                     "done", task=task, result=res, comm_build_s=comm_s,
                     resumed_from_step=ckpt.resumed_from_step if ckpt else 0))
             except Exception as e:  # noqa: BLE001 — report any payload error
+                # keep the traceback: a device fault ends up as a fail event
+                # (and maybe a retry), which must still say where it arose
                 self._q.put(ExecEvent(
-                    "fail", task=task, error=f"{type(e).__name__}: {e}",
+                    "fail", task=task,
+                    error=f"{type(e).__name__}: {e}\n{traceback.format_exc()}",
                     comm_build_s=comm_s,
                     resumed_from_step=ckpt.resumed_from_step if ckpt else 0))
 

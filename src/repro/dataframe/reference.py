@@ -13,13 +13,16 @@ def ref_join_inner(left: dict, right: dict, key: str) -> dict:
     """Inner join with duplicates, left-key-sorted output (matches
     ops_local.join_inner / ops_dist ordering after sorting)."""
     lk, rk = np.asarray(left[key]), np.asarray(right[key])
+    l_order = np.argsort(lk, kind="stable")
     r_order = np.argsort(rk, kind="stable")
-    rk_s = rk[r_order]
-    lo = np.searchsorted(rk_s, lk, side="left")
-    hi = np.searchsorted(rk_s, lk, side="right")
-    l_idx = np.repeat(np.arange(len(lk)), hi - lo)
-    r_idx = np.concatenate([r_order[a:b] for a, b in zip(lo, hi, strict=True)]) \
-        if len(lk) else np.zeros((0,), np.int64)
+    # sorted probes: each binary search starts where the previous one ended
+    lk_s, rk_s = lk[l_order], rk[r_order]
+    lo = np.searchsorted(rk_s, lk_s, side="left")
+    counts = np.searchsorted(rk_s, lk_s, side="right") - lo
+    l_idx = np.repeat(l_order, counts)
+    # pair j of sorted left row i takes right match lo[i] + (j - first pair)
+    firsts = np.cumsum(counts) - counts
+    r_idx = r_order[np.arange(len(l_idx)) + np.repeat(lo - firsts, counts)]
     out = {}
     for k, v in left.items():
         name = k if k == key else (f"l_{k}" if k in right else k)

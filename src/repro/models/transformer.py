@@ -192,24 +192,29 @@ def prefill(params, cfg, batch, smax: int, mode: AttnMode = AttnMode()):
 
 
 def decode_step(params, cfg, batch, cache):
-    """batch: tokens (B,1), positions (B,) write index. Returns (logits, cache)."""
+    """batch: tokens (B,1), positions (B,) write index. Returns (logits, cache).
+
+    The cache rides through the layer scan as carry and each layer writes
+    its new token in place, so the step holds one copy of the cache (a scan
+    emitting per-layer caches as outputs would build a second one)."""
     tokens, positions = batch["tokens"], batch["positions"]
     x = embed_apply(params["embed"], tokens)
     pos2d = positions[:, None]
+    bidx = jnp.arange(tokens.shape[0])
 
-    def block(x, blk_and_cache):
-        blk, ck, cv = blk_and_cache
+    def block(carry, blk_and_index):
+        x, ck, cv = carry
+        blk, i = blk_and_index
         period = cfg.moe_layer_period
-        nk, nv = [], []
         for j in range(period):
             ap = jax.tree.map(lambda a: a[j], blk["attn"])
             h = rms_norm(x, ap["ln"], cfg.norm_eps)
             q, k, v = attn.qkv_project(ap, h, pos2d, cfg.rope_theta,
                                        cfg.qk_norm, cfg.norm_eps)
-            ckj, cvj = attn.cache_update(ck[j], cv[j], k, v, positions)
-            o = attn.attend_decode(q, ckj, cvj, positions + 1)
+            ck = ck.at[i, j, bidx, positions].set(k[:, 0].astype(ck.dtype))
+            cv = cv.at[i, j, bidx, positions].set(v[:, 0].astype(cv.dtype))
+            o = attn.attend_decode(q, ck[i, j], cv[i, j], positions + 1)
             x = x + shard_tokens(jnp.einsum("bshk,hkd->bsd", o, ap["wo"]))
-            nk.append(ckj); nv.append(cvj)
             if cfg.n_experts and j == period - 1:
                 x = _ffn_sub(blk["moe"], x, cfg, True)
             elif cfg.n_experts and period > 1:
@@ -217,14 +222,12 @@ def decode_step(params, cfg, batch, cache):
                 x = _ffn_sub(dp, x, cfg, False)
             elif not cfg.n_experts:
                 x = _ffn_sub(blk["mlp"], x, cfg, False)
-        return x, (jnp.stack(nk), jnp.stack(nv))
+        return (x, ck, cv), None
 
-    def scan_body(x, xs):
-        return block(x, xs)
-
-    x, (nk, nv) = jax.lax.scan(scan_body, x,
-                               (params["blocks"], cache["k"], cache["v"]),
-                               unroll=scan_unroll(cfg))
+    (x, nk, nv), _ = jax.lax.scan(
+        block, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(_n_super(cfg))),
+        unroll=scan_unroll(cfg))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_apply(params["embed"], x, cfg.tie_embeddings)[:, 0]
     return logits, {"k": nk, "v": nv}

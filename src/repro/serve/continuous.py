@@ -131,8 +131,11 @@ class ContinuousEngine:
         self.max_batch = max_batch
         self.max_seq = max_seq
         self._prefix = prompt_prefix_len(cfg)
+        # the slot cache is donated to every step that replaces it, so a
+        # cache sized to fill the device never needs a second copy of itself
         self._decode = jax.jit(
-            lambda p, b, c: self.api.decode_step(p, cfg, b, c))
+            lambda p, b, c: self.api.decode_step(p, cfg, b, c),
+            donate_argnums=2)
         self._prefill = jax.jit(
             lambda p, b: self.api.prefill(p, cfg, b, max_seq, AttnMode()))
         axes, spec1 = cache_batch_axes(cfg, params, max_seq)
@@ -150,7 +153,7 @@ class ContinuousEngine:
             lambda cache, new, slot: jax.tree.map(
                 lambda c, n, ax: jax.lax.dynamic_update_slice_in_dim(
                     c, n.astype(c.dtype), slot, axis=ax),
-                cache, new, self._axes))
+                cache, new, self._axes), donate_argnums=0)
         self.slots: list[Optional[_Slot]] = [None] * max_batch
         self.queue: deque[Request] = deque()
         self.results: dict[int, np.ndarray] = {}

@@ -12,7 +12,7 @@ generation as tasks on private sub-meshes next to ETL and training tasks
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -102,13 +102,33 @@ class ServeEngine:
         return {r.uid: gen[i, :r.max_new_tokens] for i, r in enumerate(requests)}
 
 
-def greedy_reference(cfg, params, prompt: np.ndarray, n_new: int):
-    """Oracle: full forward re-run per generated token (tests)."""
+def greedy_reference(cfg, params, prompt: np.ndarray, n_new: int, *,
+                     pad_to: Optional[int] = None,
+                     return_logits: bool = False):
+    """Oracle: a jitted full forward re-run per generated token.
+
+    Each step compiles once per token-row length.  ``pad_to`` pads every
+    row to that fixed length so one compilation serves all steps; that is
+    exact only for causal families without capacity routing (the logits at
+    a position must not depend on later positions — MoE token dropping
+    does).  ``return_logits`` also returns every step's float32 logits,
+    ``(n_new, vocab)``, for a tolerance check where a low-precision stream
+    parts from the oracle."""
     api = registry.get_model(cfg)
+    step = jax.jit(lambda p, b, i: api.forward(p, cfg, b)[0, i]
+                   .astype(jnp.float32))
+    offset = prompt_prefix_len(cfg) - 1
     toks = list(map(int, prompt))
+    rows = []
     for _ in range(n_new):
-        batch = {"tokens": jnp.asarray(np.asarray(toks, np.int32)[None]),
+        row = np.asarray(toks, np.int32)
+        if pad_to is not None:
+            row = np.pad(row, (0, pad_to - len(row)))
+        batch = {"tokens": jnp.asarray(row[None]),
                  **modal_dummy_inputs(cfg, 1)}
-        logits = api.forward(params, cfg, batch)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return np.asarray(toks[len(prompt):], np.int32)
+        logits = step(params, batch, offset + len(toks))
+        toks.append(int(jnp.argmax(logits)))
+        if return_logits:
+            rows.append(np.asarray(logits))
+    out = np.asarray(toks[len(prompt):], np.int32)
+    return (out, np.stack(rows)) if return_logits else out
