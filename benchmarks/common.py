@@ -98,8 +98,8 @@ def trace_summary(report) -> dict:
         "cache_hits": kinds.get("cache_hit", 0),
     }
     # span-derived timing breakdown, present only when worker flight-recorder
-    # spans exist (process executor with instrumented workers, or a loaded
-    # trace of such a run); sim/thread reports simply omit the keys
+    # spans exist (process or thread executor, or a loaded trace of such a
+    # run); sim reports simply omit the keys
     spans = getattr(report, "spans", None) or ()
     if spans:
         from repro.obs.spans import WAIT_KINDS

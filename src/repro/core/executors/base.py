@@ -46,10 +46,13 @@ class ExecEvent:
     resumed_from_step: int = 0     # checkpoint step the attempt restored
     # before running (crash-safe resume evidence; 0 = ran from scratch,
     # max over a multi-part proc task's workers)
+    compiles: int = 0              # programs the task compiled rather than
+    # loaded from the persistent cache (thread backend; 0 elsewhere)
+    cache_loads: int = 0           # programs the task loaded from that cache
     spans: list = dataclasses.field(default_factory=list)   # worker-side
     # flight-recorder spans of a terminal event, already aligned into the
     # parent clock: [{kind, t0, t1, worker, part, uid, task}, ...]; empty
-    # on sim/thread backends — same schema, empty section
+    # on the sim backend — same schema, empty section
     worker: str = ""               # telemetry: reporting worker id
     telemetry: Optional[dict] = None   # telemetry: the gauge/counter
     # snapshot a HEARTBEAT frame carried (queue depth, RSS, spill bytes,

@@ -5,10 +5,13 @@ from __future__ import annotations
 import dataclasses
 import threading
 import traceback
+from time import perf_counter
 from typing import Any, Optional
 
 from repro.core.executors.base import ExecEvent, QueueEventExecutor
 from repro.core.task import Task
+from repro.obs import spans
+from repro.obs.device import TaskRecorder
 
 
 @dataclasses.dataclass
@@ -36,7 +39,13 @@ class StubComm:
 class ThreadExecutor(QueueEventExecutor):
     """Live executor: each task runs ``fn(comm, *args, **kwargs)`` in a
     worker thread on its allocated devices, with a freshly built private
-    Communicator (the paper's per-task MPI_Comm analogue)."""
+    Communicator (the paper's per-task MPI_Comm analogue).
+
+    Each terminal event carries the task's flight-recorder spans
+    (``launch_recv``: dispatch -> the thread runs; ``comm_build``;
+    ``compute``: the payload; ``jit_trace``/``jit_lower``/``jit_compile``
+    while it builds programs; see :mod:`repro.obs.device`) in the
+    scheduler's clock, and its ``compiles``/``cache_loads`` counts."""
 
     def __init__(self, build_comm: bool = True, tick: float = 0.05):
         super().__init__()
@@ -45,6 +54,11 @@ class ThreadExecutor(QueueEventExecutor):
 
     def launch(self, task: Task, duration_hint: Optional[float] = None):
         def worker():
+            # the task's flight recorder, bound to this thread for the whole
+            # task: its own spans, the operator-build spans and counters JAX
+            # reports while the payload builds, and profiler annotations
+            rec = TaskRecorder(task.uid)
+            rec.add("launch_recv", task.start_time, perf_counter())
             comm_s = 0.0
             ckpt = None
             if task.ckpt_dir:
@@ -53,30 +67,37 @@ class ThreadExecutor(QueueEventExecutor):
                 from repro.train.checkpoint import CheckpointContext
                 ckpt = CheckpointContext(task.ckpt_dir,
                                          attempt=task.ckpt_attempt or "a0")
-            try:
-                if self.build_comm:
-                    from repro.core.communicator import build_communicator
-                    comm = build_communicator(task.devices,
-                                              task.desc.mesh_axes,
-                                              task.desc.mesh_shape,
-                                              uid=f"task{task.uid}",
-                                              placement=task.placement)
-                    comm_s = comm.build_seconds
-                else:
-                    comm = StubComm(devices=tuple(task.devices),
-                                    placement=task.placement)
-                comm.checkpoint = ckpt
-                res = task.desc.fn(comm, *task.desc.args, **task.desc.kwargs)
-                self._q.put(ExecEvent(
-                    "done", task=task, result=res, comm_build_s=comm_s,
-                    resumed_from_step=ckpt.resumed_from_step if ckpt else 0))
-            except Exception as e:  # noqa: BLE001 — report any payload error
-                # keep the traceback: a device fault ends up as a fail event
-                # (and maybe a retry), which must still say where it arose
-                self._q.put(ExecEvent(
-                    "fail", task=task,
-                    error=f"{type(e).__name__}: {e}\n{traceback.format_exc()}",
-                    comm_build_s=comm_s,
-                    resumed_from_step=ckpt.resumed_from_step if ckpt else 0))
+            with spans.bound(rec):
+                try:
+                    if self.build_comm:
+                        from repro.core.communicator import build_communicator
+                        with rec.span("comm_build"):
+                            comm = build_communicator(task.devices,
+                                                      task.desc.mesh_axes,
+                                                      task.desc.mesh_shape,
+                                                      uid=f"task{task.uid}",
+                                                      placement=task.placement)
+                        comm_s = comm.build_seconds
+                    else:
+                        comm = StubComm(devices=tuple(task.devices),
+                                        placement=task.placement)
+                    comm.checkpoint = ckpt
+                    with rec.span("compute"):
+                        res = task.desc.fn(comm, *task.desc.args,
+                                           **task.desc.kwargs)
+                    ev = ExecEvent("done", task=task, result=res)
+                except Exception as e:  # noqa: BLE001 — report any payload error
+                    # keep the traceback: a device fault ends up as a fail
+                    # event (and maybe a retry), which must still say where
+                    # it arose
+                    ev = ExecEvent(
+                        "fail", task=task,
+                        error=f"{type(e).__name__}: {e}\n{traceback.format_exc()}")
+            ev.comm_build_s = comm_s
+            ev.resumed_from_step = ckpt.resumed_from_step if ckpt else 0
+            ev.compiles, ev.cache_loads = rec.compiles, rec.cache_loads
+            ev.spans = spans.align(rec.export(), 0.0, worker="thread", part=0,
+                                   uid=task.uid, task=task.desc.name)
+            self._q.put(ev)
 
         threading.Thread(target=worker, daemon=True).start()

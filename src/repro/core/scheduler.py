@@ -149,7 +149,8 @@ class TraceEvent:
     data: dict = dataclasses.field(default_factory=dict)
                          # kind-specific structured payload: terminal events
                          # carry {hub_calls, p2p_fallbacks, hub_relay_bytes}
-                         # (the comm-stats evidence trace_summary reports);
+                         # (the comm-stats evidence trace_summary reports)
+                         # and {compiles, cache_loads} (operator builds);
                          # telemetry events carry the worker id + its gauge
                          # snapshot.  Empty dict everywhere else — the
                          # schema never forks per backend.
@@ -169,7 +170,7 @@ class SimReport:
     trace: list = dataclasses.field(default_factory=list)
     spans: list = dataclasses.field(default_factory=list)   # worker-side
     # flight-recorder spans aligned into the executor clock; empty on
-    # backends without instrumented workers (sim/thread) — same schema
+    # the sim backend, whose tasks never run — same schema
     telemetry: list = dataclasses.field(default_factory=list)   # heartbeat
     # gauge snapshots ({t, worker, queue_depth, rss_mb, ...}); empty on
     # sim/thread backends
@@ -222,7 +223,7 @@ class SchedulerSession:
         self.running: dict[int, Task] = {}
         self.trace: list[TraceEvent] = []
         self.spans: list[dict] = []      # worker flight-recorder spans,
-        # parent-clock aligned (empty on sim/thread — same schema)
+        # parent-clock aligned (empty on sim — same schema)
         self.telemetry: list[dict] = []  # heartbeat gauge snapshots
         # durable capture: every TraceEvent/span/telemetry record streams to
         # JSONL as it happens (crash-safe line-buffered writes) when
@@ -858,7 +859,9 @@ class SchedulerSession:
                  "raw_coll_bytes": ev.raw_coll_bytes,
                  "shm_bytes": ev.shm_bytes,
                  "ring_steps": ev.ring_steps,
-                 "resumed_from_step": ev.resumed_from_step}
+                 "resumed_from_step": ev.resumed_from_step,
+                 "compiles": ev.compiles,
+                 "cache_loads": ev.cache_loads}
         if task.uid in self._ignored:
             self._ignored.discard(task.uid)
             self._dispatch()   # live twin finished after cancel: reclaim only

@@ -2,6 +2,8 @@
 MPI/UCX/GLOO communicator layer.  All distributed operators go through these
 four primitives, so the 'transport' is swappable and mockable (single point
 of instrumentation for the collective-traffic accounting in benchmarks/).
+The data exchanges run under the ``exchange`` scope, which names them in
+the compiled program's metadata.
 """
 from __future__ import annotations
 
@@ -10,11 +12,14 @@ import jax
 
 def all_to_all(x, axis: str):
     """x (P, c, ...) per rank -> chunk j goes to rank j; returns (P, c, ...)"""
-    return jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=False)
+    with jax.named_scope("exchange"):
+        return jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
+                                  tiled=False)
 
 
 def all_gather(x, axis: str):
-    return jax.lax.all_gather(x, axis)
+    with jax.named_scope("exchange"):
+        return jax.lax.all_gather(x, axis)
 
 
 def psum(x, axis: str):

@@ -12,6 +12,10 @@ runtime delivers) and runs as one jit'd shard_map program over the 'df' axis:
 Static shapes: every rank holds (capacity,) padded columns + nrows.  Send
 buffers have per-destination capacity slack; overflow is detected and
 reported (overflow flag), never silently dropped.
+
+Phases run under named scopes that the compiled program's metadata carries:
+``pack`` and ``splitters`` here, ``exchange`` in :mod:`comm`, ``argsort``,
+``permute``, ``search`` and ``gather`` in :mod:`ops_local`.
 """
 from __future__ import annotations
 
@@ -79,26 +83,27 @@ def _table_spec(axis: str):
 # ---------------------------------------------------------------------------
 def _local_shuffle_pack(table: Table, target, n_parts: int, send_cap: int):
     """Pack rows into a (P, send_cap, ...) send buffer by destination."""
-    cap = table.capacity
-    valid = table.valid_mask()
-    tgt = jnp.where(valid, target, n_parts)          # invalid -> dropped
-    order = jnp.argsort(jnp.where(valid, tgt, n_parts), stable=True)
-    sorted_t = tgt[order]
-    start = jnp.searchsorted(sorted_t, jnp.arange(n_parts), side="left")
-    pos_sorted = jnp.arange(cap) - start[jnp.minimum(sorted_t, n_parts - 1)]
-    pos = jnp.zeros_like(pos_sorted).at[order].set(pos_sorted)
-    counts = jnp.bincount(jnp.where(valid, tgt, n_parts), length=n_parts + 1)[:n_parts]
-    overflow = jnp.any(counts > send_cap)
+    with jax.named_scope("pack"):
+        cap = table.capacity
+        valid = table.valid_mask()
+        tgt = jnp.where(valid, target, n_parts)          # invalid -> dropped
+        order = jnp.argsort(jnp.where(valid, tgt, n_parts), stable=True)
+        sorted_t = tgt[order]
+        start = jnp.searchsorted(sorted_t, jnp.arange(n_parts), side="left")
+        pos_sorted = jnp.arange(cap) - start[jnp.minimum(sorted_t, n_parts - 1)]
+        pos = jnp.zeros_like(pos_sorted).at[order].set(pos_sorted)
+        counts = jnp.bincount(jnp.where(valid, tgt, n_parts), length=n_parts + 1)[:n_parts]
+        overflow = jnp.any(counts > send_cap)
 
-    bufs = {}
-    row_ok = valid & (pos < send_cap)
-    e = jnp.where(row_ok, tgt, n_parts)
-    pp = jnp.where(row_ok, pos, 0)
-    for k, v in table.columns.items():
-        buf = jnp.zeros((n_parts, send_cap) + v.shape[1:], v.dtype)
-        bufs[k] = buf.at[e, pp].set(v, mode="drop")
-    sent = jnp.minimum(counts, send_cap).astype(jnp.int32)  # (P,) rows per dest
-    return bufs, sent, overflow
+        bufs = {}
+        row_ok = valid & (pos < send_cap)
+        e = jnp.where(row_ok, tgt, n_parts)
+        pp = jnp.where(row_ok, pos, 0)
+        for k, v in table.columns.items():
+            buf = jnp.zeros((n_parts, send_cap) + v.shape[1:], v.dtype)
+            bufs[k] = buf.at[e, pp].set(v, mode="drop")
+        sent = jnp.minimum(counts, send_cap).astype(jnp.int32)  # (P,) rows per dest
+        return bufs, sent, overflow
 
 
 def _shuffle_inside(table: Table, target, axis: str, slack: float):
@@ -143,15 +148,16 @@ def _dist_sort_inside(table: Table, key: str, axis: str, slack: float):
     n_parts = comm.axis_size(axis)
     ts = L.sort_by(table, key)
     # sample n_parts values per rank at even quantiles of the VALID rows
-    q = (jnp.arange(n_parts) + 0.5) / n_parts
-    idx = jnp.clip((q * jnp.maximum(ts.nrows, 1)).astype(jnp.int32), 0,
-                   table.capacity - 1)
-    samples = ts.columns[key][idx]                       # (P,)
-    all_samples = comm.all_gather(samples, axis).reshape(-1)  # (P*P,)
-    ssorted = jnp.sort(all_samples)
-    splitters = ssorted[(jnp.arange(1, n_parts) * n_parts)]   # (P-1,)
-    target = jnp.searchsorted(splitters, ts.columns[key], side="right")
-    target = jnp.where(ts.valid_mask(), target.astype(jnp.int32), 0)
+    with jax.named_scope("splitters"):
+        q = (jnp.arange(n_parts) + 0.5) / n_parts
+        idx = jnp.clip((q * jnp.maximum(ts.nrows, 1)).astype(jnp.int32), 0,
+                       table.capacity - 1)
+        samples = ts.columns[key][idx]                       # (P,)
+        all_samples = comm.all_gather(samples, axis).reshape(-1)  # (P*P,)
+        ssorted = jnp.sort(all_samples)
+        splitters = ssorted[(jnp.arange(1, n_parts) * n_parts)]   # (P-1,)
+        target = jnp.searchsorted(splitters, ts.columns[key], side="right")
+        target = jnp.where(ts.valid_mask(), target.astype(jnp.int32), 0)
     shuffled, ovf = _shuffle_inside(ts, target, axis, slack)
     return L.sort_by(shuffled, key), ovf
 

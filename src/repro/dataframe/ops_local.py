@@ -1,6 +1,10 @@
 """Cylon 'local operators': run on locally resident data only.
 All static-shape: outputs are (capacity,)-padded with explicit nrows and an
 overflow flag where the logical result size is data-dependent (join).
+
+Each phase runs under a ``jax.named_scope`` (``argsort``, ``permute``,
+``search``, ``gather``), which names its operations in the compiled
+program's metadata and leaves the computation as it is.
 """
 from __future__ import annotations
 
@@ -26,17 +30,24 @@ def masked_key(table: Table, key: str) -> jnp.ndarray:
 
 def sort_by(table: Table, key: str) -> Table:
     """Stable local sort by key; invalid rows stay at the end."""
-    order = jnp.argsort(masked_key(table, key), stable=True)
-    cols = {k: v[order] for k, v in table.columns.items()}
-    return Table(columns=cols, nrows=table.nrows)
+    with jax.named_scope("argsort"):
+        order = jnp.argsort(masked_key(table, key), stable=True)
+    return Table(columns=permute(table.columns, order), nrows=table.nrows)
+
+
+def permute(columns: dict, order) -> dict:
+    """Every column taken in ``order``."""
+    with jax.named_scope("permute"):
+        return {k: v[order] for k, v in columns.items()}
 
 
 def filter_rows(table: Table, keep: jnp.ndarray) -> Table:
     """Compact rows where keep & valid (stable)."""
     keep = keep & table.valid_mask()
-    order = jnp.argsort(~keep, stable=True)  # kept rows first, stable
-    cols = {k: v[order] for k, v in table.columns.items()}
-    return Table(columns=cols, nrows=jnp.sum(keep).astype(jnp.int32))
+    with jax.named_scope("argsort"):
+        order = jnp.argsort(~keep, stable=True)  # kept rows first, stable
+    return Table(columns=permute(table.columns, order),
+                 nrows=jnp.sum(keep).astype(jnp.int32))
 
 
 def project(table: Table, names) -> Table:
@@ -68,37 +79,39 @@ def join_inner(left: Table, right: Table, key: str, out_capacity: int):
     """
     ls = sort_by(left, key)
     rs = sort_by(right, key)
-    lk = masked_key(ls, key)
-    rk = masked_key(rs, key)
-    lo = jnp.searchsorted(rk, lk, side="left")
-    hi = jnp.searchsorted(rk, lk, side="right")
-    # clamp matches against invalid right rows
-    hi = jnp.minimum(hi, rs.nrows)
-    lo = jnp.minimum(lo, rs.nrows)
-    counts = jnp.where(ls.valid_mask(), hi - lo, 0)
-    ends = jnp.cumsum(counts)
-    total = ends[-1]
-    starts = ends - counts
+    with jax.named_scope("search"):
+        lk = masked_key(ls, key)
+        rk = masked_key(rs, key)
+        lo = jnp.searchsorted(rk, lk, side="left")
+        hi = jnp.searchsorted(rk, lk, side="right")
+        # clamp matches against invalid right rows
+        hi = jnp.minimum(hi, rs.nrows)
+        lo = jnp.minimum(lo, rs.nrows)
+        counts = jnp.where(ls.valid_mask(), hi - lo, 0)
+        ends = jnp.cumsum(counts)
+        total = ends[-1]
+        starts = ends - counts
 
-    out_idx = jnp.arange(out_capacity)
-    li = jnp.searchsorted(ends, out_idx, side="right")      # left row of pair j
-    li_c = jnp.minimum(li, ls.capacity - 1)
-    ri = lo[li_c] + (out_idx - starts[li_c])
-    valid_out = out_idx < jnp.minimum(total, out_capacity)
-    li_g = jnp.where(valid_out, li_c, 0)
-    ri_g = jnp.where(valid_out, jnp.minimum(ri, rs.capacity - 1), 0)
+        out_idx = jnp.arange(out_capacity)
+        li = jnp.searchsorted(ends, out_idx, side="right")  # left row of pair j
+        li_c = jnp.minimum(li, ls.capacity - 1)
+        ri = lo[li_c] + (out_idx - starts[li_c])
+        valid_out = out_idx < jnp.minimum(total, out_capacity)
+        li_g = jnp.where(valid_out, li_c, 0)
+        ri_g = jnp.where(valid_out, jnp.minimum(ri, rs.capacity - 1), 0)
 
     cols = {}
-    for k, v in ls.columns.items():
-        name = k if k == key else (f"l_{k}" if k in rs.columns else k)
-        cols[name] = jnp.where(
-            _expand(valid_out, v.ndim), v[li_g], jnp.zeros_like(v[li_g]))
-    for k, v in rs.columns.items():
-        if k == key:
-            continue
-        name = f"r_{k}" if k in ls.columns else k
-        cols[name] = jnp.where(
-            _expand(valid_out, v.ndim), v[ri_g], jnp.zeros_like(v[ri_g]))
+    with jax.named_scope("gather"):
+        for k, v in ls.columns.items():
+            name = k if k == key else (f"l_{k}" if k in rs.columns else k)
+            cols[name] = jnp.where(
+                _expand(valid_out, v.ndim), v[li_g], jnp.zeros_like(v[li_g]))
+        for k, v in rs.columns.items():
+            if k == key:
+                continue
+            name = f"r_{k}" if k in ls.columns else k
+            cols[name] = jnp.where(
+                _expand(valid_out, v.ndim), v[ri_g], jnp.zeros_like(v[ri_g]))
     out = Table(columns=cols,
                 nrows=jnp.minimum(total, out_capacity).astype(jnp.int32))
     return out, total > out_capacity

@@ -7,7 +7,7 @@ Produces the JSON object format (``{"traceEvents": [...]}``) both
   complete events; concurrent parts get separate ``tid`` lanes),
 * a ``scheduler`` process whose lanes carry the dispatch->terminal slice of
   every task (reconstructed from the event stream — present even for
-  span-less sim/thread traces) plus instant markers for the pool-level
+  span-less sim traces) plus instant markers for the pool-level
   events (grow/retire/device_failure/steal/return),
 * counter tracks (``C`` events) for every telemetry gauge a worker
   reported — queue depth, RSS, spill bytes, peer-channel cache size,

@@ -1,21 +1,23 @@
 """Worker-side span tracing: the lightweight timing API the flight recorder
 instruments task execution with.
 
-A *span* is one timed section of a task part — ``launch_recv``,
-``deserialize``, ``comm_build``, ``compute``, ``p2p_send``, ``p2p_recv``,
-``spill_write``, ``merge`` — recorded as ``(kind, t0, t1)`` in the worker's
+A *span* is one timed section of a task part (the kinds in
+:data:`SPAN_KINDS`), recorded as ``(kind, t0, t1)`` in the worker's
 ``perf_counter`` clock.  :class:`SpanRecorder` collects them with near-zero
 overhead (two clock reads and a list append per span; no locks on the hot
 path beyond a plain list, which is append-safe under the GIL), ships them
 back piggybacked on the PART_DONE frame, and the parent aligns them into its
 own clock with the per-worker offset established during the HELLO handshake
-(see ``executors/proc.py``).
+(see ``executors/proc.py``).  The thread backend runs in the parent's clock
+and ships its tasks' spans on the terminal event with offset 0
+(``executors/thread.py``, :class:`repro.obs.device.TaskRecorder`).
 
-Deeply-nested code (``shuffle.SpillBuffer`` spilling inside a payload) does
-not thread a recorder through every call: the worker binds the part's
-recorder to the *thread* running the payload (:func:`set_current` /
-:func:`current_recorder`), and un-instrumented contexts get a no-op recorder
-— sim/thread backends produce empty span sections, never schema drift.
+Deeply-nested code (``shuffle.SpillBuffer`` spilling inside a payload, JAX's
+build reports in :mod:`repro.obs.device`) does not thread a recorder through
+every call: the worker binds the part's recorder to the *thread* running the
+payload (:func:`set_current` / :func:`current_recorder`), and
+un-instrumented contexts get a no-op recorder — the sim backend produces
+empty span sections, never schema drift.
 """
 from __future__ import annotations
 
@@ -34,6 +36,9 @@ SPAN_KINDS = (
     "p2p_recv",       # waiting for a peer frame / hub collective result
     "spill_write",    # writing a spilled shuffle run to disk
     "merge",          # streaming k-way merge of spilled runs
+    "jit_trace",      # JAX tracing a program (from JAX's report, obs/device)
+    "jit_lower",      # JAX lowering the traced program to MLIR
+    "jit_compile",    # backend compile, or a load from the compile cache
 )
 
 #: span kinds that are *waits* (time the part was blocked on someone else),
@@ -71,9 +76,9 @@ class SpanRecorder:
 
 
 class NullRecorder(SpanRecorder):
-    """No-op recorder bound outside an instrumented part (sim/thread
-    payloads, direct calls in tests): the ``span`` blocks run, nothing is
-    kept — un-instrumented code pays two clock reads and nothing else."""
+    """No-op recorder bound outside an instrumented part (sim payloads,
+    direct calls in tests): the ``span`` blocks run, nothing is kept —
+    un-instrumented code pays two clock reads and nothing else."""
 
     def add(self, kind: str, t0: float, t1: float):
         pass

@@ -4,8 +4,8 @@
 worker span, and every telemetry snapshot to a JSONL file as they happen —
 one JSON object per line, line-buffered, so a SIGKILLed run still yields a
 readable prefix (the crash-forensics contract).  The schema is identical on
-all three executor backends; sim/thread runs simply contain no span or
-telemetry lines.
+all three executor backends; sim runs simply contain no span or telemetry
+lines, thread runs no telemetry lines.
 
 Line types::
 
