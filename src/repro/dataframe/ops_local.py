@@ -4,7 +4,11 @@ overflow flag where the logical result size is data-dependent (join).
 
 Each phase runs under a ``jax.named_scope`` (``argsort``, ``permute``,
 ``search``, ``gather``), which names its operations in the compiled
-program's metadata and leaves the computation as it is.
+program's metadata and leaves the computation as it is.  The join's
+``search`` merges the two sorted key arrays by one stable sort and expands
+each left row's matches into output slots by scatter-adds and running
+sums: it has no binary search, whose every step gathers one value for each
+row.
 """
 from __future__ import annotations
 
@@ -75,30 +79,17 @@ def join_inner(left: Table, right: Table, key: str, out_capacity: int):
     """Sort-merge inner join with duplicate keys.
 
     Returns (Table, overflow: bool array).  Non-key columns are prefixed
-    l_/r_ on name collision.  Output order: left-key sorted, stable.
+    l_/r_ on name collision.  Output order: left-key sorted, stable; each
+    left row's right matches in stable key order.
     """
     ls = sort_by(left, key)
     rs = sort_by(right, key)
     with jax.named_scope("search"):
-        lk = masked_key(ls, key)
-        rk = masked_key(rs, key)
-        lo = jnp.searchsorted(rk, lk, side="left")
-        hi = jnp.searchsorted(rk, lk, side="right")
-        # clamp matches against invalid right rows
-        hi = jnp.minimum(hi, rs.nrows)
-        lo = jnp.minimum(lo, rs.nrows)
-        counts = jnp.where(ls.valid_mask(), hi - lo, 0)
-        ends = jnp.cumsum(counts)
-        total = ends[-1]
-        starts = ends - counts
-
-        out_idx = jnp.arange(out_capacity)
-        li = jnp.searchsorted(ends, out_idx, side="right")  # left row of pair j
-        li_c = jnp.minimum(li, ls.capacity - 1)
-        ri = lo[li_c] + (out_idx - starts[li_c])
-        valid_out = out_idx < jnp.minimum(total, out_capacity)
-        li_g = jnp.where(valid_out, li_c, 0)
-        ri_g = jnp.where(valid_out, jnp.minimum(ri, rs.capacity - 1), 0)
+        li, ri, total = _match_pairs(masked_key(ls, key), masked_key(rs, key),
+                                     ls.nrows, rs.nrows, out_capacity)
+        valid_out = jnp.arange(out_capacity) < jnp.minimum(total, out_capacity)
+        li_g = jnp.where(valid_out, li, 0)
+        ri_g = jnp.where(valid_out, ri, 0)
 
     cols = {}
     with jax.named_scope("gather"):
@@ -115,6 +106,49 @@ def join_inner(left: Table, right: Table, key: str, out_capacity: int):
     out = Table(columns=cols,
                 nrows=jnp.minimum(total, out_capacity).astype(jnp.int32))
     return out, total > out_capacity
+
+
+def _match_pairs(lk, rk, l_nrows, r_nrows, out_capacity):
+    """Output slot j's left row ``li`` and right row ``ri``, for j below
+    the number of matching pairs, which is returned as well.  ``lk`` and
+    ``rk`` are sorted keys; rows from ``l_nrows`` and ``r_nrows`` on are
+    padding.
+
+    One stable sort of the right keys followed by the left ones merges
+    them: every right row comes before the left rows of its key, and left
+    rows keep their order.  A running count of valid right rows then reads
+    each left row's matches ``[lo, hi)``: ``hi`` at the row, ``lo`` where
+    its key's run begins.  A running sum of the counts gives each left
+    row's first output slot, ``starts``.
+
+    Slot j belongs to the last row whose first slot is at most j, and
+    ``ri = j + lo - starts`` of that row.  Both are running sums over the
+    slots of steps added at each row's first slot: 1 at a left row for
+    ``li``, and the change in ``lo - starts`` from the row before for
+    ``ri``.  Right rows and left rows without matches share the next
+    row's first slot, so their steps add up to that row's values.
+    """
+    r_cap = rk.shape[0]
+    keys, pos = jax.lax.sort(
+        (jnp.concatenate([rk, lk]),
+         jnp.arange(r_cap + lk.shape[0], dtype=jnp.int32)), is_stable=True)
+    is_left = pos >= r_cap
+    valid_right = (pos < r_nrows).astype(jnp.int32)
+    hi = jnp.cumsum(valid_right)
+    run_start = jnp.concatenate([jnp.ones(1, bool), keys[1:] != keys[:-1]])
+    lo = jax.lax.cummax(jnp.where(run_start, hi - valid_right, 0))
+    counts = jnp.where(is_left & (pos - r_cap < l_nrows), hi - lo, 0)
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+
+    def running(step):
+        slots = jnp.zeros(out_capacity, step.dtype).at[starts].add(
+            step, mode="drop", indices_are_sorted=True)
+        return jnp.cumsum(slots)
+
+    li = running(is_left.astype(jnp.int32)) - 1
+    ri = running(jnp.diff(lo - starts, prepend=0))
+    return li, ri + jnp.arange(out_capacity, dtype=ri.dtype), ends[-1]
 
 
 def _expand(mask, ndim):

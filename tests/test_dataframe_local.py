@@ -1,6 +1,7 @@
 """Local dataframe operators vs numpy oracles — hypothesis property tests."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tests._hypothesis_compat import given, settings, st
 
@@ -78,6 +79,82 @@ def test_filter_compacts_stably(keep):
     got = out.to_numpy()
     want = data["k"][np.asarray(keep)]
     np.testing.assert_array_equal(got["k"], want)
+
+
+def _ordered_join_oracle(left, ln, right, rn, out_capacity):
+    """join_inner's output in order: valid left rows in stable key order,
+    each followed by its valid right matches in stable key order, cut to
+    out_capacity and zero-padded; plus nrows and the overflow flag."""
+    lk, rk = left["k"][:ln], right["k"][:rn]
+    lo_, ro_ = np.argsort(lk, kind="stable"), np.argsort(rk, kind="stable")
+    pairs = [(i, j) for i in lo_ for j in ro_ if rk[j] == lk[i]]
+    kept = pairs[:out_capacity]
+    out = {}
+    for name, src, col in (("k", left, 0), ("v", left, 0), ("w", right, 1)):
+        buf = np.zeros(out_capacity, src[name].dtype)
+        buf[:len(kept)] = [src[name][p[col]] for p in kept]
+        out[name] = buf
+    return out, len(kept), len(pairs) > out_capacity
+
+
+def _ordered_join_case(case, rng):
+    """(left, ln, right, rn): padded columns of two tables and their rows."""
+    lcap, rcap = 23, 17
+    dtype = np.float32 if case == "float_keys" else np.int32
+    ln, rn = int(rng.integers(8, lcap - 2)), int(rng.integers(6, rcap - 2))
+    if case == "no_matches":
+        lkeys = 2 * rng.integers(0, 6, lcap)
+        rkeys = 2 * rng.integers(0, 6, rcap) + 1
+    elif case == "float_keys":
+        # 0.0 and -0.0 are one key
+        pool = np.asarray([-1.5, -0.0, 0.0, 2.25, 7.0], np.float32)
+        lkeys, rkeys = rng.choice(pool, lcap), rng.choice(pool, rcap)
+    else:
+        lkeys, rkeys = rng.integers(0, 5, lcap), rng.integers(0, 5, rcap)
+    if case == "sentinel_keys":
+        top = np.iinfo(np.int32).max
+        lkeys[rng.random(lcap) < 0.4] = top
+        rkeys[rng.random(rcap) < 0.4] = top
+    if case == "empty_left":
+        ln = 0
+    if case == "empty_right":
+        rn = 0
+    left = {"k": lkeys.astype(dtype),
+            "v": rng.normal(size=lcap).astype(np.float32)}
+    right = {"k": rkeys.astype(dtype),
+             "w": rng.normal(size=rcap).astype(np.float32)}
+    return left, ln, right, rn
+
+
+@pytest.mark.parametrize("fit", ["below", "at", "above"])
+@pytest.mark.parametrize("case", ["duplicates", "no_matches", "empty_left",
+                                  "empty_right", "sentinel_keys",
+                                  "float_keys"])
+def test_join_inner_output_in_order_bit_for_bit(case, fit):
+    """join_inner's output, nrows and overflow flag equal the ordered
+    oracle's exactly, padding included; a short out_capacity keeps the
+    same prefix and raises the flag."""
+    rng = np.random.default_rng(sum(map(ord, case + fit)))
+    for _ in range(4):
+        left, ln, right, rn = _ordered_join_case(case, rng)
+        lt = Table(columns={k: jnp.asarray(v) for k, v in left.items()},
+                   nrows=jnp.int32(ln))
+        rt = Table(columns={k: jnp.asarray(v) for k, v in right.items()},
+                   nrows=jnp.int32(rn))
+        total = _ordered_join_oracle(left, ln, right, rn, 10**4)[1]
+        cap = {"below": max(total - 3, 1), "at": max(total, 1),
+               "above": total + 6}[fit]
+        want, nrows, overflow = _ordered_join_oracle(left, ln, right, rn, cap)
+        out, got_overflow = L.join_inner(lt, rt, "k", cap)
+        assert sorted(out.columns) == sorted(want)
+        for name, col in want.items():
+            got = np.asarray(out.columns[name])
+            assert got.dtype == col.dtype, name
+            np.testing.assert_array_equal(got.view(np.uint32),
+                                          col.view(np.uint32), err_msg=name)
+        assert int(out.nrows) == nrows
+        assert bool(got_overflow) == overflow
+        assert overflow == (fit == "below" and total > 1)
 
 
 def test_join_overflow_flag():
