@@ -23,6 +23,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from chipbench import harness  # noqa: E402
 from chipbench import tables as traffic_gen  # noqa: E402
+from chipbench.ops import _tables  # noqa: E402
 
 
 def lower_precision(tables: list) -> list:
@@ -36,7 +37,8 @@ def lower_precision(tables: list) -> list:
 
 def readings(cell, seed: int) -> dict:
     key = cell.config["key"]
-    host = traffic_gen.make_tables(cell.config, cell.traffic, cell.rows, seed)
+    host = traffic_gen.make_tables(cell.config, cell.traffic,
+                                   _tables.rows(cell), seed)
     ref = cell.op.reference(host, key)
     got = cell.op.reference(lower_precision(host), key)
     checks = {"rows_out_diff": abs(len(got[key]) - len(ref[key])),

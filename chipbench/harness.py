@@ -1,19 +1,48 @@
 """The benchmark's core: find a cell by name, set it up, time its window of
-pilot tasks, check what the window produced, and assemble the result.
+operations, check what the window produced, and assemble the result.
 
-Everything particular to one configuration, traffic mix, operator or
-per-layer metric sits in a file of its own, found by the names in
+Everything particular to one configuration, traffic mix, kind of operation
+or per-layer metric sits in a file of its own, found by the names in
 ``BENCHMARK.json``:
 
-* ``chipbench/configs/<config>.json``: schema, rows a rank (one chip is
-  one rank), operator options (the file ``BENCHMARK.json`` names);
-* ``chipbench/traffic/<traffic>.json``: key distributions, read by
-  :mod:`chipbench.tables`;
-* ``chipbench/ops/<op>.py``: the operator's ``build``, the task body, its
-  reference, its comparison and its bytes function (``op`` is named by the
-  configuration);
+* ``chipbench/configs/<config>.json``: the deployment's sizes and options
+  (the file ``BENCHMARK.json`` names); its ``op`` names the op module;
+* ``chipbench/traffic/<traffic>.json``: the parameters of the traffic mix,
+  read by the op module;
+* ``chipbench/ops/<op>.py``: the op protocol, the only names the harness
+  reads from an op module:
+
+  - ``setup(cell, seed, comm) -> state``: makes the inputs from the
+    configuration, the traffic and the seed, places them on the cell's
+    chips (``comm`` is a communicator over them) and compiles every program
+    the window runs, into the persistent cache; set-up ends when it
+    returns;
+  - ``operation(session, state) -> (uids, output, facts)``: one timed
+    operation.  It submits pilot tasks through the ``SchedulerSession``,
+    waits for them and returns their uids, the output the sampler may keep
+    (``None`` when the operation failed) and a dict of facts about it for
+    ``check``.  An exception ends the run with no result;
+  - ``check(state, kept, ops) -> {name: number or (number, limit)}``:
+    after the window, the comparison with the op's plain reference of the
+    kept output and of the window's :class:`Op` records.  A bare number has
+    the limit 0 (an exact comparison); a pair states its own limit, as a
+    loss or a norm compared with a reference needs.  A number over its
+    limit makes the run not correct.  The harness holds no other reference
+    to the kept output or to the program's state by then, so ``check`` can
+    free them before its reference runs;
+  - ``least_time(cell, state, peaks) -> (seconds, bound)``: the least time
+    of one operation on the device, called after ``check``;
+
+  an op with one program of fixed inputs also gives ``build`` (the
+  program, for a mesh and the configuration) and ``input_shapes(cell,
+  mesh)``, its abstract arguments, which ``rehearse.py`` and the phase
+  readers compile (``ops/_tables.py`` holds them for the table operators);
 * ``chipbench/layer_metrics/<metric>.py``: ``read(run)`` returns the
   metric from a :class:`Run`, or ``None`` when it finds nothing to read.
+
+The harness itself keeps what every cell shares: the device check, the
+pilot and its session, the closed loop of the window, the sampler, the
+profiler trace, the device memory peak, ``ops_failed`` and the result line.
 """
 from __future__ import annotations
 
@@ -28,13 +57,10 @@ import time
 from pathlib import Path
 from typing import Optional
 
-import numpy as np
-
-from chipbench import tables as traffic_gen
+from chipbench.tables import rng_for
 
 ROOT = Path(__file__).resolve().parents[1]
 HERE = Path(__file__).resolve().parent
-OP_TIMEOUT_S = 900.0        # one task that takes longer has hung
 JIT_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
               "/jax/core/compile/jaxpr_to_mlir_module_duration",
               "/jax/core/compile/backend_compile_duration")
@@ -63,11 +89,6 @@ class Cell:
     op: object                      # the module chipbench/ops/<op>.py
     end_to_end: list                # metric entries this cell reports
     per_layer: list                 # (entry, reader module) pairs
-
-    @property
-    def rows(self) -> int:
-        """Rows a table: the configuration's rows a rank, times the chips."""
-        return self.config["rows_per_rank"] * self.chips
 
 
 def reports(metric: dict, cell: str, e2e_names=None) -> bool:
@@ -130,49 +151,22 @@ def check_device(chips: int) -> dict:
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class Op:
-    uid: int
-    t0: float                  # host clock at submit
-    t1: float                  # host clock when the task's end was seen
+    uids: tuple                # the pilot tasks of the operation
+    t0: float                  # host clock at its start
+    t1: float                  # host clock when its end was seen
     ok: bool
-    rows_out: int = -1
-    overflow: bool = False
+    facts: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
 class Run:
     """What the per-layer readers read."""
     cell: Cell
-    ops: list                  # Op of each window task
-    events: list               # the scheduler's TraceEvents of those tasks
+    ops: list                  # Op of each window operation
+    events: list               # the scheduler's TraceEvents of their tasks
     jit: list                  # (host clock, event, seconds) in the window
     least_s: float             # least time of one operation
     trace: Optional[object] = None   # chipbench.xplane.DeviceTrace
-
-
-def row_bytes(schema: dict) -> int:
-    return sum(np.dtype(t).itemsize for t in schema.values())
-
-
-def least_time(cell: Cell, rows_out: int, peaks: dict) -> tuple:
-    schemas = cell.config["tables"]
-    out_bytes = row_bytes({k: t for s in schemas for k, t in s.items()})
-    hbm, ici = cell.op.least_bytes(
-        [cell.rows] * len(schemas), [row_bytes(s) for s in schemas],
-        rows_out, out_bytes, cell.chips)
-    t_hbm = hbm / peaks["hbm_bytes_per_s"]
-    t_ici = ici / peaks["ici_bytes_per_s"]
-    return (t_ici, "ici") if t_ici > t_hbm else (t_hbm, "hbm")
-
-
-def collect(table) -> dict:
-    """A distributed output table on the host: each rank's valid rows, in
-    rank order."""
-    nrows = np.asarray(table.nrows).reshape(-1)
-    out = {}
-    for name, col in table.columns.items():
-        per_rank = np.asarray(col).reshape((len(nrows), -1))
-        out[name] = np.concatenate([per_rank[r, :n] for r, n in enumerate(nrows)])
-    return out
 
 
 class JitListener:
@@ -217,11 +211,11 @@ class JitListener:
 
 
 class Sampler:
-    """Keeps the output of one window task, drawn uniformly from the seed
-    (reservoir of one), so every task's output may be the one compared."""
+    """Keeps the output of one window operation, drawn uniformly from the
+    seed (reservoir of one), so every output may be the one compared."""
 
     def __init__(self, seed: int):
-        self.rng = traffic_gen.rng_for(seed, stream=1)
+        self.rng = rng_for(seed, stream=1)
         self.seen = 0
         self.kept = None
 
@@ -230,28 +224,22 @@ class Sampler:
         if self.rng.random() < 1.0 / self.seen:
             self.kept = out
 
+    def take(self):
+        """The kept output, which the sampler then no longer holds."""
+        kept, self.kept = self.kept, None
+        return kept
 
-def run_op(session, desc, ops: list):
-    """Submit one task, wait for it, record it in ``ops`` and return its
-    output table (``None`` when the task failed)."""
+
+def run_op(cell: Cell, session, state, ops: list):
+    """One operation, recorded in ``ops``; returns its output (``None``
+    when it failed)."""
     from jax.profiler import TraceAnnotation
     with TraceAnnotation("chipbench/op"):
         t0 = time.perf_counter()
-        (task,) = session.submit([desc])
-        finished = session.wait_any(timeout=OP_TIMEOUT_S)
+        uids, out, facts = cell.op.operation(session, state)
         t1 = time.perf_counter()
-    if not finished:
-        raise RuntimeError(f"task {desc.name} did not end in {OP_TIMEOUT_S}s")
-    rec = Op(uid=task.uid, t0=t0, t1=t1, ok=task.result is not None)
-    ops.append(rec)
-    if not rec.ok:
-        print(f"chipbench: task {task.uid} failed: {task.error}",
-              file=sys.stderr, flush=True)
-        return None
-    out, overflow = task.result
-    task.result = None                # the session keeps every Task
-    rec.rows_out = int(np.asarray(out.nrows).sum())
-    rec.overflow = bool(overflow)
+    ops.append(Op(uids=tuple(uids), t0=t0, t1=t1, ok=out is not None,
+                  facts=facts))
     return out
 
 
@@ -269,32 +257,21 @@ def _run_cell(cell, seed, seconds, trace, t_start, jit) -> dict:
     import jax
     from jax.profiler import TraceAnnotation
     from repro.core import (PilotDescription, PilotManager, SchedulerSession,
-                            TaskDescription, ThreadExecutor,
-                            build_communicator)
-    from repro.dataframe import ops_dist as D
+                            ThreadExecutor, build_communicator)
 
     devices = jax.devices()
-    config, chips, rows, key = cell.config, cell.chips, cell.rows, cell.config["key"]
 
-    # set-up: tables from the seed, placed on the cell's chips, and the
-    # operator compiled for them (into the persistent cache) without a run
-    host = traffic_gen.make_tables(config, cell.traffic, rows, seed)
+    # set-up: the op's inputs from the seed, placed on the cell's chips, and
+    # its programs compiled (into the persistent cache) without a run
     rm = PilotManager().submit_pilot(
-        PilotDescription(n_devices=chips)).resource_manager
+        PilotDescription(n_devices=cell.chips)).resource_manager
     cell_devices = rm.all_devices
-    comm = build_communicator(cell_devices)
-    cap = rows // chips * 2 + 64
-    resident = [D.shard_table(comm, t, cap) for t in host]
-    cell.op.build(comm.mesh, config, "return").lower(*resident).compile()
-    comm = None
+    state = cell.op.setup(cell, seed, build_communicator(cell_devices))
     session = SchedulerSession(ThreadExecutor(), rm, result_cache="0",
                                ckpt_root="")
-    desc = TaskDescription(name=cell.op.TASK, ranks=chips, fn=cell.op.payload,
-                           args=(resident, config), max_retries=0,
-                           tags={"pipeline": cell.name})
     setup_s = time.perf_counter() - t_start
 
-    # the window: a closed loop of one task at a time
+    # the window: a closed loop of one operation at a time
     trace_dir = None
     if trace:
         import tempfile
@@ -307,7 +284,7 @@ def _run_cell(cell, seed, seconds, trace, t_start, jit) -> dict:
     with TraceAnnotation("chipbench/window"):
         w0 = time.perf_counter()
         while not ops or time.perf_counter() - w0 < seconds:
-            out = run_op(session, desc, ops)
+            out = run_op(cell, session, state, ops)
             if out is not None:
                 sampler.offer(out)
             out = None
@@ -315,39 +292,29 @@ def _run_cell(cell, seed, seconds, trace, t_start, jit) -> dict:
     if trace:
         jax.profiler.stop_trace()
     report = session.close()
-    window_uids = {o.uid for o in ops}
+    window_uids = {u for o in ops for u in o.uids}
     events = [e for e in report.trace if e.uid in window_uids]
     bad = [e.kind for e in report.trace
            if e.kind in ("retry", "fail", "cache_hit")]
 
-    # device memory first: nothing after this allocates on the chips
+    # device memory first: nothing after this allocates on the chips for
+    # the program
     stats = [d.memory_stats() or {} for d in cell_devices]
     peaks_b = [s["peak_bytes_in_use"] for s in stats if "peak_bytes_in_use" in s]
     memory_peak = max(peaks_b) if peaks_b else None
-    got = collect(sampler.kept) if sampler.seen else None
-    # free the program's state (the tables, the kept output, the tasks that
-    # hold them) before the reference runs
-    sampler = resident = desc = session = report = None
+    # the kept output goes to the op's check as its only reference, so the
+    # check can free it and the program's state before its reference runs
+    session = report = None
     gc.collect()
-
-    ref = cell.op.reference(host, key)
-    ref_rows = len(ref[key])
-    checks = {
-        "ops_failed": sum(not o.ok for o in ops) + len(bad),
-        "overflow": sum(o.overflow for o in ops),
-        "rows_out_diff": max((abs(o.rows_out - ref_rows)
-                              for o in ops if o.ok), default=ref_rows),
-    }
-    if got is None:
-        checks["rows_mismatched"] = ref_rows
-    else:
-        checks.update(cell.op.compare(got, ref, key))
-    host = ref = got = None
+    checks = {"ops_failed": sum(not o.ok for o in ops) + len(bad),
+              **cell.op.check(state, sampler.take(), ops)}
+    checks = {k: v if isinstance(v, tuple) else (v, 0)
+              for k, v in checks.items()}
 
     n_ok = sum(o.ok for o in ops)
     window_s = w1 - w0
     kind = devices[0].device_kind
-    least_s, bound = least_time(cell, ref_rows, load_peaks(kind))
+    least_s, bound = cell.op.least_time(cell, state, load_peaks(kind))
     print(f"chipbench: {cell.name} seed={seed} ops={len(ops)} "
           f"window_s={window_s!r} setup_s={setup_s!r} least_s={least_s!r} "
           f"({bound}) compiles_in_window={jit.compiles_between(w0, w1)} "
@@ -355,7 +322,7 @@ def _run_cell(cell, seed, seconds, trace, t_start, jit) -> dict:
           file=sys.stderr, flush=True)
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": memory_peak}
-    result = {"correct": all(v <= 0 for v in checks.values()),
+    result = {"correct": all(v <= limit for v, limit in checks.values()),
               "attempted": len(ops), "failed": len(ops) - n_ok}
     if trace:
         import shutil
@@ -380,5 +347,6 @@ def _run_cell(cell, seed, seconds, trace, t_start, jit) -> dict:
                              for m in cell.end_to_end
                              if values.get(m["name"]) is not None}
         result["device"] = device
-    result["checks"] = {k: {"value": v, "limit": 0} for k, v in checks.items()}
+    result["checks"] = {k: {"value": v, "limit": limit}
+                        for k, (v, limit) in checks.items()}
     return result

@@ -4,12 +4,13 @@ operation.  Shared by the ``<phase>_ms`` readers.
 The program runs each phase under a ``jax.named_scope``; the compiled
 program's metadata (``op_name="jit(_join)/search/..."``) carries it, the
 device trace does not (its event names are HLO text without metadata, its
-event stats times only).  So the cell's operator is compiled once more for
-the tables the run placed, after the window; each instruction of its HLO
+event stats times only).  So the cell's operator (its op module's
+``build``) is compiled once more for the inputs the run placed (its
+``input_shapes``), after the window; each instruction of its HLO
 text is given the innermost phase its ``op_name`` names, and each device
 operation in a window operation's span counts its own time (without what
 runs nested in it) toward its instruction's phase.  A program without the
-scopes gives nothing to read.
+phase's scope, or an op without one such program, gives nothing to read.
 """
 from __future__ import annotations
 
@@ -21,6 +22,10 @@ PHASES = ("pack", "exchange", "splitters", "argsort", "permute", "search",
           "gather")
 INSTRUCTION = re.compile(
     r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?metadata=\{op_name="([^"]*)"', re.M)
+# a phase as a scope in the lowered program's locations: the whole name
+# (``loc("jit(_join)/search/lt")``) or, inside a ``shard_map`` over several
+# chips, one relative to it (``loc("search"(#loc4))``)
+SCOPED = re.compile(r'loc\("(?:[^"]*/)?(?:%s)[/"]' % "|".join(PHASES))
 _last: list = [None, None]           # the trace last read, and its phases
 
 
@@ -49,27 +54,17 @@ def phase_seconds(trace, phases: dict, lo: float, hi: float) -> dict:
 
 
 def compiled_text(cell) -> str:
-    """HLO text of the cell's operator compiled for the tables the run
-    placed: ``rows // chips * 2 + 64`` slots a rank on the cell's first
-    chips, as the harness places them."""
+    """HLO text of the cell's operator compiled for the inputs the run
+    placed on the cell's first chips."""
     import jax
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import build_communicator
-    from repro.dataframe.table import Table
 
-    comm = build_communicator(jax.devices()[:cell.chips])
-    rows = cell.rows // cell.chips * 2 + 64
-    spec = NamedSharding(comm.mesh, P("df"))
-    tables = [Table(columns={k: jax.ShapeDtypeStruct(
-                        (cell.chips * rows,), np.dtype(t), sharding=spec)
-                             for k, t in schema.items()},
-                    nrows=jax.ShapeDtypeStruct((cell.chips,), np.int32,
-                                               sharding=spec))
-              for schema in cell.config["tables"]]
-    lowered = cell.op.build(comm.mesh, cell.config, "return").lower(*tables)
-    names = lowered.as_text(debug_info=True)
-    if not any(f"/{p}/" in names for p in PHASES):
+    if not hasattr(cell.op, "input_shapes"):
+        return ""                   # an op without one such program
+    mesh = build_communicator(jax.devices()[:cell.chips]).mesh
+    lowered = cell.op.build(mesh, cell.config, "return").lower(
+        *cell.op.input_shapes(cell, mesh))
+    if not SCOPED.search(lowered.as_text(debug_info=True)):
         return ""                   # a program without the scopes
     # the persistent cache's key leaves metadata out, so by default it may
     # hand back a program compiled from the same operations under other
@@ -89,8 +84,8 @@ def read(run, phase: str):
     if _last[0] is not run.trace:
         _last[:] = [run.trace, phases_of(compiled_text(run.cell))]
     phases = _last[1]
-    if not phases:
-        return None
+    if phase not in phases.values():
+        return None                 # the program has no such phase
     times = [phase_seconds(run.trace, phases, lo, hi).get(phase, 0.0)
              for lo, hi in run.trace.spans("chipbench/op")]
     return 1e3 * sum(times) / len(times) if times else None

@@ -1,0 +1,7 @@
+"""Operators: device time under the ``splitters`` scope per window operation
+(see ``_phase``)."""
+from chipbench.layer_metrics._phase import read as _read
+
+
+def read(run):
+    return _read(run, "splitters")

@@ -1,10 +1,13 @@
 """Inner join of two tables: the task body, its plain reference, its
-comparison and the bytes it has to move."""
+comparison and the bytes it has to move.  The op protocol's set-up,
+operation, check and least time are the table ones of ``_tables``."""
 from __future__ import annotations
 
 import numpy as np
 
-from chipbench.compare import rows_mismatched
+from chipbench.compare import by_key_range, pool, range_bounds, rows_mismatched
+from chipbench.ops._tables import (check, input_shapes, least_time,  # noqa: F401
+                                   operation, setup)
 
 TASK = "dist_join"
 
@@ -33,8 +36,18 @@ def payload(comm, tables, config):
 def reference(tables: list, key: str) -> dict:
     """Every pair of a left and a right row with equal keys.  A left
     column keeps its name, a right one too unless the left has it, when
-    the two become ``l_<name>`` and ``r_<name>``."""
+    the two become ``l_<name>`` and ``r_<name>``.  Rows of one key meet
+    only in one key range, so the ranges are joined apart, in host
+    threads."""
     left, right = tables
+    bounds = range_bounds(left[key])
+    with pool() as threads:
+        lp, rp = (by_key_range(t, key, bounds, threads) for t in tables)
+        parts = list(threads.map(lambda a, b: _join(a, b, key), lp, rp))
+    return {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
+
+
+def _join(left: dict, right: dict, key: str) -> dict:
     lk, rk = left[key], right[key]
     l_order, r_order = np.argsort(lk), np.argsort(rk)
     lk_s, rk_s = lk[l_order], rk[r_order]

@@ -1,10 +1,13 @@
 """Global sort of one table by its key: the task body, its plain reference,
-its comparison and the bytes it has to move."""
+its comparison and the bytes it has to move.  The op protocol's set-up,
+operation, check and least time are the table ones of ``_tables``."""
 from __future__ import annotations
 
 import numpy as np
 
-from chipbench.compare import rows_mismatched
+from chipbench.compare import by_key_range, pool, range_bounds, rows_mismatched
+from chipbench.ops._tables import (check, input_shapes, least_time,  # noqa: F401
+                                   operation, setup)
 
 TASK = "dist_sort"
 
@@ -30,7 +33,17 @@ def payload(comm, tables, config):
 
 
 def reference(tables: list, key: str) -> dict:
+    """The rows in key order, rows of one key in their input order: each
+    key range sorted apart, in host threads, and the ranges in order."""
     (table,) = tables
+    with pool() as threads:
+        parts = list(threads.map(
+            lambda t: _sorted(t, key),
+            by_key_range(table, key, range_bounds(table[key]), threads)))
+    return {k: np.concatenate([p[k] for p in parts]) for k in table}
+
+
+def _sorted(table: dict, key: str) -> dict:
     order = np.argsort(table[key], kind="stable")
     return {k: v[order] for k, v in table.items()}
 

@@ -4,9 +4,10 @@ without the chip, and print what the compiler plans for one chip's memory.
     JAX_PLATFORMS=cpu python3 chipbench/rehearse.py [--workload <cell> ...]
 
 The compile runs in this process on the TPU compiler (a one-chip mesh or
-the 2x2 host), with the operator built by the cell's ``ops/<op>.py``.  It
-proves that the program compiles and fits; it runs nothing.  One JSON line
-a cell, with ``memory_analysis()`` in bytes.
+the 2x2 host), with the operator built by the cell's ``ops/<op>.py``
+(``build``) for the abstract inputs its set-up places (``input_shapes``).
+It proves that the program compiles and fits; it runs nothing.  One JSON
+line a cell, with ``memory_analysis()`` in bytes.
 """
 import argparse
 import json
@@ -21,23 +22,12 @@ from chipbench import harness  # noqa: E402
 
 
 def compile_cell(cell, topo) -> dict:
-    import jax
     import numpy as np
-    from jax.sharding import Mesh, NamedSharding
-    from jax.sharding import PartitionSpec as P
-    from repro.dataframe.table import Table
+    from jax.sharding import Mesh
 
-    chips, config = cell.chips, cell.config
-    mesh = Mesh(np.array(topo.devices[:chips]), ("df",))
-    rows = NamedSharding(mesh, P("df"))
-    cap = cell.rows // chips * 2 + 64
-    args = [Table(columns={k: jax.ShapeDtypeStruct((chips * cap,), t,
-                                                   sharding=rows)
-                           for k, t in schema.items()},
-                  nrows=jax.ShapeDtypeStruct((chips,), "int32", sharding=rows))
-            for schema in config["tables"]]
-    fn = cell.op.build(mesh, config, "return")
-    mem = fn.lower(*args).compile().memory_analysis()
+    mesh = Mesh(np.array(topo.devices[:cell.chips]), ("df",))
+    fn = cell.op.build(mesh, cell.config, "return")
+    mem = fn.lower(*cell.op.input_shapes(cell, mesh)).compile().memory_analysis()
     fields = ("argument_size_in_bytes", "output_size_in_bytes",
               "alias_size_in_bytes", "temp_size_in_bytes",
               "generated_code_size_in_bytes")
@@ -65,7 +55,7 @@ def main(argv=None):
         cell = harness.load_cell(name)
         cell.chips = args.chips or cell.chips
         print(json.dumps({"workload": name, "chips": cell.chips,
-                          "rows": cell.rows, **compile_cell(cell, topo)}),
+                          **compile_cell(cell, topo)}),
               flush=True)
 
 

@@ -1,18 +1,19 @@
-"""Chip benchmark of the pilot's distributed join and sort.
+"""Chip benchmark of the pilot's operations: the distributed join and sort,
+and whatever op a cell's configuration names.
 
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One process, run from the root of a checkout.  It checks the device (a
 platform other than ``tpu``, too few chips, or a device kind missing from
 ``chipbench/peaks.json`` exits non-zero with no result), turns on the
-persistent compile cache, makes the cell's tables from the seed, places
-them on the cell's chips, compiles the cell's operator for them without
-running it (set-up ends here), then times a closed loop of pilot tasks,
-one at a time, until ``--seconds`` have passed and the last task has
-ended.  It compares the output with its
-own reference and prints one JSON line: the end-to-end metrics with
-``--trace 0``, the per-layer metrics read from a profiler trace of the
-window with ``--trace 1``.
+persistent compile cache, and runs the cell's op (``chipbench/ops/<op>.py``,
+see ``chipbench/harness.py``): its set-up makes the inputs from the seed,
+places them on the cell's chips and compiles its programs without running
+them (set-up ends here); then a closed loop of operations, one at a time,
+runs until ``--seconds`` have passed and the last operation has ended.  The
+op compares the output with its own reference, and the run prints one JSON
+line: the end-to-end metrics with ``--trace 0``, the per-layer metrics read
+from a profiler trace of the window with ``--trace 1``.
 """
 import time
 

@@ -15,11 +15,15 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from chipbench import harness, tables, xplane  # noqa: E402
+from chipbench import compare, harness, tables, xplane  # noqa: E402
 from chipbench.compare import rows_mismatched  # noqa: E402
+from chipbench.ops import _tables  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
+#: the cells whose op is a table operator, with one program of fixed inputs
+TABLE_CELLS = [w for w in CELLS
+               if hasattr(harness.load_cell(w).op, "input_shapes")]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -32,53 +36,118 @@ def one_line(text: str) -> bool:
 # ---------------------------------------------------------------------------
 # BENCHMARK.json
 # ---------------------------------------------------------------------------
-def test_benchmark_json_keys_and_characters():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def check_benchmark(root: Path):
+    """BENCHMARK.json under ``root`` has the contract's keys, names and
+    characters.  Shared with the protocol test, which runs it over the
+    real file with a model cell added."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    cells = [w["name"] for w in bench["workloads"]]
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert len((ROOT / "BENCHMARK.json").read_bytes()) <= 64 * 1024
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert all(one_line(w) for w in BENCH["command"])
-    for p in BENCH["paths"]:
+    assert len((root / "BENCHMARK.json").read_bytes()) <= 64 * 1024
+    assert 1 <= bench["run_seconds"] <= 51
+    assert all(one_line(w) for w in bench["command"])
+    for p in bench["paths"]:
         assert re.fullmatch(r"[A-Za-z0-9_./-]{1,200}", p) and ".." not in p
-        assert (ROOT / p).is_dir()
-    for c in BENCH["configs"]:
+        assert (root / p).is_dir()
+    for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and one_line(c["source"])
         assert one_line(c["why"]) and all(NAME.match(k) for k in c["reduced"])
-        assert any(c["file"].startswith(p + "/") for p in BENCH["paths"])
-    for w in BENCH["workloads"]:
+        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+    for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["chips"] in (1, 4) and one_line(w["why"])
-    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
+    assert len(cells) == len(set(cells))
+    assert 1 <= len(bench["command"]) <= 32 and 1 <= len(bench["paths"]) <= 16
+    assert 1 <= len(bench["configs"]) <= 24 and 1 <= len(cells) <= 24
+    assert 1 <= len(bench["end_to_end"]) <= 16
+    assert 1 <= len(bench["per_layer"]) <= 128
+    configs = [c["name"] for c in bench["configs"]]
+    files = [c["file"] for c in bench["configs"]]
+    assert len(configs) == len(set(configs)) and len(files) == len(set(files))
+    assert all(len(c["reduced"]) <= 16 for c in bench["configs"])
+    used = [w["config"] for w in bench["workloads"]]
+    assert set(used) == set(configs)
+    # a pair of configuration and traffic mix is one cell, never two
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
+    assert len(pairs) == len(set(pairs))
+    metrics = bench["end_to_end"] + bench["per_layer"]
     names = [m["name"] for m in metrics]
     assert len(names) == len(set(names))
-    for m in BENCH["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
                                           "source"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
-    for m in BENCH["per_layer"]:
+    for m in bench["per_layer"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
                                           "layer", "moves"}
         assert one_line(m["layer"]) and m["moves"] in names
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
+        assert set(m.get("workloads", cells)) <= set(cells)
     for m in metrics:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
     assert "setup_s" in names
-    n_four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert n_four <= max(1, len(CELLS) // 2)
+    n_four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert n_four <= max(1, len(cells) // 2)
+
+
+def check_cell(cell: str, root: Path):
+    """The cell loads by name from the files under ``root``, its op gives
+    the protocol's names, and it reports ``setup_s``, another end-to-end
+    metric and a per-layer metric.  Nothing here is particular to one kind
+    of op: a model cell passes it too."""
+    c = harness.load_cell(cell, root=root)
+    for name in ("setup", "operation", "check", "least_time"):
+        assert callable(getattr(c.op, name)), name
+    e2e = {m["name"] for m in c.end_to_end}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert c.per_layer and all(callable(r.read) for _, r in c.per_layer)
+
+
+def check_table_cell(cell: str, root: Path):
+    """A table cell's op also gives the operator, its task body, its
+    reference and its abstract inputs, and sizes its tables by rows a
+    rank."""
+    c = harness.load_cell(cell, root=root)
+    assert _tables.rows(c) == c.config["rows_per_rank"] * c.chips
+    for name in ("input_shapes", "build", "payload", "reference"):
+        assert callable(getattr(c.op, name)), name
+
+
+def test_benchmark_json_keys_and_characters():
+    check_benchmark(ROOT)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_finds_its_files(cell):
+    check_cell(cell, ROOT)
+
+
+@pytest.mark.parametrize("cell", TABLE_CELLS)
+def test_every_table_cell_gives_its_table_parts(cell):
+    check_table_cell(cell, ROOT)
+
+
+@pytest.mark.parametrize("cell", ["join-uniform-1chip", "sort-uniform-1chip"])
+def test_set_up_places_what_input_shapes_describes(cell):
+    """The abstract inputs that rehearse.py and the phase readers compile
+    are the tables set-up places, so the three compile one program."""
+    import jax
+    from repro.core import build_communicator
     c = harness.load_cell(cell)
-    assert c.rows == c.config["rows_per_rank"] * c.chips
-    assert callable(c.op.payload) and callable(c.op.reference)
-    assert callable(c.op.build)
-    assert {m["name"] for m in c.end_to_end} >= {"setup_s", "op_time_s"}
-    assert c.per_layer and all(callable(r.read) for _, r in c.per_layer)
+    c.config["rows_per_rank"] = 300
+    comm = build_communicator(jax.devices()[:1])
+    state = c.op.setup(c, 3, comm)
+    shapes = c.op.input_shapes(c, comm.mesh)
+    placed, abstract = jax.tree.leaves(state.resident), jax.tree.leaves(shapes)
+    assert len(placed) == len(abstract) == 3 * len(c.config["tables"])
+    for x, y in zip(placed, abstract):
+        assert (x.shape, x.dtype, x.sharding) == (y.shape, y.dtype, y.sharding)
+    fn = c.op.build(comm.mesh, c.config, "return")
+    assert fn.lower(*state.resident).as_text() == fn.lower(*shapes).as_text()
 
 
 def test_adding_a_cell_edits_no_existing_file(tmp_path):
@@ -197,6 +266,25 @@ def test_compare_is_exact_and_order_free():
     assert rows_mismatched({"k": ref["k"], "v": ref["v"]}, ref, "k") == n
 
 
+@pytest.mark.parametrize("dist", [{"dist": "uniform", "range": 1.0},
+                                  {"dist": "zipf", "a": 1.2, "range": 1.0}])
+def test_references_by_key_range_equal_the_whole_at_once(dist):
+    """Split into key ranges and worked in threads, the references give the
+    rows that one pass over the whole tables gives: the sort exactly, the
+    join as a multiset."""
+    join = harness.load_cell("join-uniform-1chip")
+    sort = harness.load_cell("sort-uniform-1chip")
+    host = tables.make_tables(join.config, {"keys": [dist]}, 40_000, 9)
+    assert len(compare.range_bounds(host[0]["k"])) > 1
+    whole = join.op._join(*host, "k")
+    got = join.op.reference(host, "k")
+    assert len(got["k"]) == len(whole["k"])
+    assert rows_mismatched(got, whole, "k") == 0
+    order = np.argsort(host[0]["k"], kind="stable")
+    got = sort.op.reference(host[:1], "k")
+    assert all(np.array_equal(got[c], host[0][c][order]) for c in host[0])
+
+
 def test_bytes_functions():
     join = harness.load_cell("join-uniform-1chip").op
     sort = harness.load_cell("sort-uniform-1chip").op
@@ -214,14 +302,17 @@ def test_least_time_names_its_bound():
     peaks = json.loads((ROOT / "chipbench" / "peaks.json").read_text())
     v5e = peaks["devices"]["TPU v5 lite"]
     one = harness.load_cell("join-uniform-1chip")
-    t, bound = harness.least_time(one, one.rows, v5e)
+    rows = _tables.rows(one)
+    state = _tables.State(cell=one, host=None, resident=None, ref_rows=rows)
+    t, bound = one.op.least_time(one, state, v5e)
     assert bound == "hbm"
-    assert t == pytest.approx(one.rows * (16 + 12) / v5e["hbm_bytes_per_s"])
+    assert t == pytest.approx(rows * (16 + 12) / v5e["hbm_bytes_per_s"])
     four = dataclasses.replace(one, chips=4)
-    t, bound = harness.least_time(four, four.rows, v5e)
+    rows = _tables.rows(four)
+    state.ref_rows = rows
+    t, bound = four.op.least_time(four, state, v5e)
     assert bound == "ici"
-    assert t == pytest.approx(four.rows * 16 / 4 * 3 / 4
-                              / v5e["ici_bytes_per_s"])
+    assert t == pytest.approx(rows * 16 / 4 * 3 / 4 / v5e["ici_bytes_per_s"])
 
 
 # ---------------------------------------------------------------------------

@@ -114,6 +114,16 @@ def test_phase_readers_find_nothing_without_scopes(monkeypatch, name):
                                          least_s=0.0)) is None
 
 
+@pytest.mark.parametrize("name", ["pack_ms", "gather_ms", "splitters_ms"])
+def test_phase_readers_find_nothing_for_a_phase_the_program_lacks(
+        monkeypatch, name):
+    """No instruction of the program is under the phase (here ``gather``
+    and ``splitters``; ``pack`` holds only ``permute``): nothing to read,
+    not 0."""
+    monkeypatch.setattr(_phase, "compiled_text", lambda cell: HLO)
+    assert reader(name).read(synthetic_run()) is None
+
+
 def test_op_compiles_reads_the_terminal_events():
     from repro.core import TraceEvent
     run = harness.Run(cell=None, ops=[], jit=[], least_s=0.0, events=[
@@ -130,7 +140,7 @@ def test_op_compiles_reads_the_terminal_events():
     ("join-uniform-1chip", {"pack", "argsort", "permute", "search", "gather"}),
     ("sort-uniform-1chip", {"pack", "argsort", "permute"})])
 def test_compiled_operator_names_its_phases(cell, phases):
-    """The operator compiled for a cell's placed tables (tiny here, on one
+    """The operator compiled for a cell's placed inputs (tiny here, on one
     CPU device, where no exchange is left) names the phases."""
     c = harness.load_cell(cell)
     c.config["rows_per_rank"] = 500

@@ -151,32 +151,89 @@ FOUR_CHIPS = r"""
 import json, sys
 sys.path[:0] = [sys.argv[1], sys.argv[1] + "/src"]
 from chipbench import harness
+from chipbench.layer_metrics import _phase
 from repro.dataframe import comm
 peaks = json.load(open(sys.argv[1] + "/chipbench/peaks.json"))
 harness.load_peaks = lambda kind: peaks["devices"]["TPU v5 lite"]
-# the join configuration on one 4-chip communicator, whichever cells
-# BENCHMARK.json lists
-cell = harness.load_cell("join-uniform-1chip")
-cell.chips, cell.config["rows_per_rank"] = 4, 500
+cell = harness.load_cell(sys.argv[2])
+cell.config["rows_per_rank"] = 500
 ok = harness.run_cell(cell, 2**31 + 5, 0.0, False, 0.0)
+phases = sorted(set(_phase.phases_of(_phase.compiled_text(cell)).values()))
 comm.all_to_all = lambda x, axis: x          # the exchange left out
 broken = harness.run_cell(cell, 2**31 + 5, 0.0, False, 0.0)
-print(json.dumps([ok["correct"], broken["correct"], ok["checks"],
-                  broken["checks"]]))
+print(json.dumps([cell.chips, ok["correct"], broken["correct"], ok["checks"],
+                  broken["checks"], phases]))
 """
 
 
-def test_four_chip_cell_on_four_host_devices():
+@pytest.mark.parametrize("cell,phases,fails", [
+    ("join-uniform-4chip", {"pack", "exchange", "argsort", "permute",
+                            "search", "gather"}, "rows_mismatched"),
+    ("sort-uniform-4chip", {"pack", "exchange", "splitters", "argsort",
+                            "permute"}, "order_breaks")])
+def test_four_chip_cell_on_four_host_devices(cell, phases, fails):
+    """The four-chip cell, loaded by name, at 500 rows a rank on four host
+    devices: correct, and not correct once the exchange is left out (the
+    join then pairs only the rows that share a rank, and each rank of the
+    sort holds keys from the whole range); its operator names the phases
+    its per-layer metrics read."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    r = subprocess.run([sys.executable, "-c", FOUR_CHIPS, str(ROOT)],
+    r = subprocess.run([sys.executable, "-c", FOUR_CHIPS, str(ROOT), cell],
                        env=env, capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-3000:]
-    ok, broken, ok_checks, broken_checks = json.loads(
+    chips, ok_correct, broken_correct, ok, broken, found = json.loads(
         r.stdout.strip().splitlines()[-1])
-    assert ok is True and all(v["value"] == 0 for v in ok_checks.values())
-    assert broken is False
-    assert broken_checks["rows_mismatched"]["value"] > 0
+    assert chips == 4
+    assert ok_correct is True and all(c["value"] == 0 for c in ok.values()), ok
+    assert broken_correct is False
+    assert broken[fails]["value"] > broken[fails]["limit"], broken
+    assert phases <= set(found)
+
+
+FOUR_CHIP_FAULTS = r"""
+import importlib.util, json, sys
+sys.path[:0] = [sys.argv[1], sys.argv[1] + "/src"]
+spec = importlib.util.spec_from_file_location("faults", sys.argv[2])
+t = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(t)
+from chipbench import control, harness
+harness.load_peaks = lambda kind: t.V5E
+got = {}
+for fault in ("unchanged", "half", "altered"):
+    cell = harness.load_cell(sys.argv[3])
+    cell.config["rows_per_rank"] = 500
+    real = cell.op.payload
+    cell.op.payload = {"unchanged": t._unchanged, "half": t._half(real),
+                       "altered": t._altered(real)}[fault]
+    r = harness.run_cell(cell, 2**31 + 7, 0.0, False, 0.0)
+    cell.op.payload = real
+    got[fault] = [r["correct"], r["checks"]]
+cell = harness.load_cell(sys.argv[3])
+cell.config["rows_per_rank"] = 500
+ctrl = control.readings(cell, 2**31 + 7)
+got["control"] = [ctrl["correct"],
+                  {k: {"value": v, "limit": 0} for k, v in ctrl["checks"].items()}]
+print(json.dumps(got))
+"""
+
+
+@pytest.mark.parametrize("cell", ["join-uniform-4chip", "sort-uniform-4chip"])
+def test_four_chip_faults_and_control_are_not_correct(cell):
+    """On four host devices, the four-chip cell's timed path broken (its
+    input handed back, half the rows left out, a value altered where it is
+    produced) and its control each turn ``correct`` false."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", FOUR_CHIP_FAULTS, str(ROOT),
+                        __file__, cell],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert set(got) == {"unchanged", "half", "altered", "control"}
+    for fault, (correct, checks) in got.items():
+        assert correct is False, fault
+        assert any(c["value"] > c["limit"] for c in checks.values()), fault
 
 
 def test_sampler_keeps_each_output_with_equal_chance():
